@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from repro.api import DeploymentSpec, deploy, plan
 from repro.core import EdgeTPUModel
 from repro.models.cnn import REAL_CNNS, synthetic_cnn
-from repro.models.layers import GraphModel
+from repro.models.layers import GraphModel, build_stage_fns
 
 MIB = 2 ** 20
 
@@ -54,9 +54,7 @@ def main(smoke: bool = False) -> None:
 
     dep = deploy(
         DeploymentSpec(stages=3, strategy="balanced_norefine"), graph=g,
-        stage_fn_builder=lambda p: [
-            (lambda layers: lambda b: m.apply_subset(params, b, layers))(ls)
-            for ls in p.stage_layers])
+        stage_fn_builder=lambda p: build_stage_fns(m, params, p))
     with dep.executor() as ex:
         outs, _ = ex.run_batch([{GraphModel.INPUT: x}])
     err = float(jnp.max(jnp.abs(outs[0][m.output] - direct)))

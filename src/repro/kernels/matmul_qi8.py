@@ -31,10 +31,11 @@ def _kernel(x_ref, w_ref, o_ref, acc_ref, *, nk: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...].astype(jnp.int32)
-    w = w_ref[...].astype(jnp.int32)
+    # int8 operands straight into the MXU, int32 accumulation (Mosaic
+    # refuses an int32 x int32 matmul)
     acc_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+        x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32)
 
     @pl.when(pl.program_id(2) == nk - 1)
     def _flush():

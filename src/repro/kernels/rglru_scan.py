@@ -17,6 +17,7 @@ h0: (B, R) fp32.  Outputs: hidden sequence (B, S, R) + final carry.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -30,13 +31,23 @@ def _kernel(a_ref, g_ref, h0_ref, y_ref, hout_ref, h_ref,
     def _init():
         h_ref[...] = h0_ref[...].astype(jnp.float32)
 
-    def step(t, h):
-        h = a_ref[:, t, :].astype(jnp.float32) * h + \
-            g_ref[:, t, :].astype(jnp.float32)
-        y_ref[:, t, :] = h.astype(y_ref.dtype)
+    # time sits on the sublane axis: load and store whole 8-row groups at
+    # aligned offsets (Mosaic refuses a dynamic single-row index there) and
+    # unroll the recurrence over the rows of a group
+    rows = math.gcd(chunk, 8)
+
+    def step(i, h):
+        t = pl.ds(pl.multiple_of(i * rows, rows), rows)
+        a = a_ref[:, t, :].astype(jnp.float32)
+        g = g_ref[:, t, :].astype(jnp.float32)
+        ys = []
+        for j in range(rows):
+            h = a[:, j, :] * h + g[:, j, :]
+            ys.append(h)
+        y_ref[:, t, :] = jnp.stack(ys, axis=1).astype(y_ref.dtype)
         return h
 
-    h = jax.lax.fori_loop(0, chunk, step, h_ref[...])
+    h = jax.lax.fori_loop(0, chunk // rows, step, h_ref[...])
     h_ref[...] = h
 
     @pl.when(pl.program_id(0) == n_chunks - 1)

@@ -17,6 +17,7 @@ Outputs y: (B, H, S, D) + final state (B, H, D, D).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -26,23 +27,37 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref,
             st_ref, *, chunk: int, n_chunks: int):
+    # the state is carried transposed, T = S^T (V x K), so every per-step
+    # operand stays a (1, D) row: the decay scales T's columns and the
+    # rank-1 update is a contraction over a length-1 axis
     @pl.when(pl.program_id(2) == 0)
     def _init():
         st_ref[...] = s0_ref[0, 0].astype(jnp.float32)
 
-    u = u_ref[0].astype(jnp.float32)                     # (D,)
+    u = u_ref[0].astype(jnp.float32)                     # (1, K)
+    rows = math.gcd(chunk, 8)
+    outer = (((0,), (0,)), ((), ()))                     # (1,V),(1,K)->(V,K)
+    rowdot = (((1,), (1,)), ((), ()))                    # (1,K),(V,K)->(1,V)
 
-    def step(t, state):
-        r = r_ref[0, 0, t].astype(jnp.float32)           # (D,)
-        k = k_ref[0, 0, t].astype(jnp.float32)
-        v = v_ref[0, 0, t].astype(jnp.float32)
-        w = w_ref[0, 0, t].astype(jnp.float32)
-        kv = k[:, None] * v[None, :]                     # (K, V) rank-1
-        y = jnp.einsum("k,kv->v", r, state + u[:, None] * kv)
-        y_ref[0, 0, t] = y.astype(y_ref.dtype)
-        return w[:, None] * state + kv
+    def step(i, state):
+        # 8-row groups at aligned offsets (Mosaic refuses a dynamic
+        # single-row index on the sublane axis), unrolled per row
+        t = pl.ds(pl.multiple_of(i * rows, rows), rows)
+        rs, ks, vs, ws = (ref[0, 0, t, :].astype(jnp.float32)
+                          for ref in (r_ref, k_ref, v_ref, w_ref))
+        ys = []
+        for j in range(rows):
+            r, k, v, w = (x[j:j + 1] for x in (rs, ks, vs, ws))
+            kv = jax.lax.dot_general(v, k, outer,
+                                     preferred_element_type=jnp.float32)
+            y = jax.lax.dot_general(r, state, rowdot,
+                                    preferred_element_type=jnp.float32)
+            ys.append(y + jnp.sum(r * u * k, axis=1, keepdims=True) * v)
+            state = state * w + kv
+        y_ref[0, 0, t, :] = jnp.concatenate(ys, axis=0).astype(y_ref.dtype)
+        return state
 
-    state = jax.lax.fori_loop(0, chunk, step, st_ref[...])
+    state = jax.lax.fori_loop(0, chunk // rows, step, st_ref[...])
     st_ref[...] = state
 
     @pl.when(pl.program_id(2) == n_chunks - 1)
@@ -68,7 +83,7 @@ def rwkv6_scan(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
             pl.BlockSpec((1, 1, chunk, d), lambda bb, hh, c: (bb, hh, c, 0)),
             pl.BlockSpec((1, 1, chunk, d), lambda bb, hh, c: (bb, hh, c, 0)),
             pl.BlockSpec((1, 1, chunk, d), lambda bb, hh, c: (bb, hh, c, 0)),
-            pl.BlockSpec((1, d), lambda bb, hh, c: (hh, 0)),
+            pl.BlockSpec((1, 1, d), lambda bb, hh, c: (hh, 0, 0)),
             pl.BlockSpec((1, 1, d, d), lambda bb, hh, c: (bb, hh, 0, 0)),
         ],
         out_specs=[
@@ -81,5 +96,5 @@ def rwkv6_scan(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, w, u, s0)
-    return y, s_last
+    )(r, k, v, w, u[:, None, :], jnp.swapaxes(s0, -1, -2))
+    return y, jnp.swapaxes(s_last, -1, -2)

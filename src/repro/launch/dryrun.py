@@ -32,22 +32,6 @@ from repro.optim import AdamWConfig                # noqa: E402
 HBM_PER_CHIP = 16 * 1024**3          # v5e
 
 
-def _normalize_cost_analysis(raw: Any) -> Dict[str, float]:
-    """``Compiled.cost_analysis()`` returns a dict on older jaxlib but a
-    *list of per-computation dicts* on newer releases; fold either shape
-    into one flat {metric: summed value} dict."""
-    if raw is None:
-        return {}
-    if isinstance(raw, dict):
-        return raw
-    merged: Dict[str, float] = {}
-    for entry in raw:
-        for k, v in (entry or {}).items():
-            if isinstance(v, (int, float)):
-                merged[k] = merged.get(k, 0.0) + v
-    return merged
-
-
 def _sharded_leaf_bytes(leaf, sh, mesh) -> float:
     """Per-device bytes of one array under its NamedSharding."""
     n = float(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
@@ -214,7 +198,7 @@ def dryrun_cell(arch: str, shape_name: str, multi_pod: bool,
         t_compile = time.time() - t0 - t_lower
 
     mem = _mem_dict(compiled.memory_analysis())
-    raw_cost = _normalize_cost_analysis(compiled.cost_analysis())
+    raw_cost = compiled.cost_analysis()
     totals = analyze(compiled.as_text())
     n_dev = rec["n_devices"]
 

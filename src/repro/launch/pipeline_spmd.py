@@ -70,13 +70,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-if hasattr(jax, "shard_map"):                      # jax >= 0.6
-    _shard_map = jax.shard_map
-    _SHMAP_NOCHECK = {"check_vma": False}
-else:                                              # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHMAP_NOCHECK = {"check_rep": False}
-
 from ..core.placement import PlacementPlan
 from ..models.layers import GraphModel
 
@@ -172,6 +165,17 @@ def _gpipe_outputs(stage_apply: Callable[[jax.Array], jax.Array],
     return outputs
 
 
+def _last_stage_block(out: jax.Array) -> jax.Array:
+    """The last stage's ``(m, mb, ...)`` block of a stage-sharded pipeline
+    output, read from that stage's own device: the other stages' blocks
+    are never gathered (indexing the global array would need them)."""
+    n = out.shape[0]
+    for shard in out.addressable_shards:
+        if shard.index[0].indices(n)[1] == n:
+            return shard.data[-1]
+    raise RuntimeError("this process holds no shard of the last stage")
+
+
 # ---------------------------------------------------------------------------
 # LM lowering: contiguous block ranges, unpadded uneven stages
 # ---------------------------------------------------------------------------
@@ -252,9 +256,9 @@ def make_pipeline_hidden(cfg, mesh: Mesh, plan: PlacementPlan,
     m = n_microbatches
     apply_builder = _lm_stage_apply_builder(cfg, counts)
 
-    @functools.partial(_shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(P(stage_axis), P(), P()),
-                       out_specs=P(stage_axis), **_SHMAP_NOCHECK)
+                       out_specs=P(stage_axis), check_vma=False)
     def pipe(blocks_sh, x_all, positions):
         blocks_l = jax.tree.map(lambda a: a[0], blocks_sh)
         sid = jax.lax.axis_index(stage_axis)
@@ -282,7 +286,7 @@ def make_pipeline_hidden(cfg, mesh: Mesh, plan: PlacementPlan,
         with warnings.catch_warnings():
             warnings.filterwarnings("ignore", message=_DONATION_NOISE)
             out = pipe_jit(stage_blocks, x_mb, positions)
-        return jax.device_get(out[-1]).reshape(b, s, d)
+        return jax.device_get(_last_stage_block(out)).reshape(b, s, d)
 
     return hidden_fn
 
@@ -458,9 +462,9 @@ class _CnnLowering:
         self.stacked_host = np.stack(
             [np.pad(f, (0, wmax - f.size)) for f in flats])   # (S, Wmax)
 
-        @functools.partial(_shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=(P(stage_axis), P()),
-                           out_specs=P(stage_axis), **_SHMAP_NOCHECK)
+                           out_specs=P(stage_axis), check_vma=False)
         def pipe(weights_sh, x_all):
             w_row = weights_sh[0]
             sid = jax.lax.axis_index(stage_axis)
@@ -693,7 +697,8 @@ class SpmdPipelineExecutor:
                     out = compiled(weights, x_all)
                 else:
                     out = low.pipe_jit(weights, x_all)
-            return low.unpack_output(jax.device_get(out[-1]), b)
+            return low.unpack_output(
+                jax.device_get(_last_stage_block(out)), b)
 
         devs = _stage_devices(mesh, stage_axis)
         mb_probe = max(1, (batch_size or m) // m)
@@ -740,9 +745,9 @@ class SpmdPipelineExecutor:
         stacked_host = jax.tree.map(np.asarray, stacked_dev)
         rest = {k: v for k, v in params.items() if k != "blocks"}
 
-        @functools.partial(_shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=(P(stage_axis), P(), P()),
-                           out_specs=P(stage_axis), **_SHMAP_NOCHECK)
+                           out_specs=P(stage_axis), check_vma=False)
         def pipe(blocks_sh, x_all, positions):
             blocks_l = jax.tree.map(lambda a: a[0], blocks_sh)
             sid = jax.lax.axis_index(stage_axis)
@@ -800,7 +805,7 @@ class SpmdPipelineExecutor:
                     out = compiled(blocks_glb, x_mb, positions)
                 else:
                     out = pipe_jit(blocks_glb, x_mb, positions)
-            h = jax.device_get(out[-1]).reshape(bp, s, d)
+            h = jax.device_get(_last_stage_block(out)).reshape(bp, s, d)
             return unembed_jit(rest, jnp.asarray(h))[:b]
 
         devs = _stage_devices(mesh, stage_axis)

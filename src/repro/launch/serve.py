@@ -31,6 +31,7 @@ from repro.api import DeploymentSpec, deploy
 from repro.configs.common import concrete_batch
 from repro.core.pipeline import (PipelineExecutor, ShapeKeyedStageCache,
                                  stage_balance_metrics)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api, lm, lm_graph
 
 
@@ -310,6 +311,7 @@ def main() -> None:
                     help="synthetic whole-model service time per fleet "
                          "member (sleep-based stage fns)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.fleet:
         run_fleet(args)

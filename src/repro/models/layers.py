@@ -18,6 +18,7 @@ used by the paper's Table 1.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -128,6 +129,12 @@ class GraphModel:
             needed[self.output] = acts[self.output]
         return needed
 
+    def stage_program(self, layer_names: Sequence[str]) -> Callable:
+        """``apply_subset`` over ``layer_names`` as one jitted program
+        ``(params, boundary) -> outputs``: what each pipeline stage runs."""
+        return jax.jit(functools.partial(self.apply_subset,
+                                         layer_names=tuple(layer_names)))
+
     # -- lowering to the segmentation representation ----------------------------
     def to_layer_graph(self) -> LayerGraph:
         g = LayerGraph(self.name)
@@ -137,6 +144,52 @@ class GraphModel:
             g.add_layer(name, params=node.params_count, macs=node.macs,
                         out_bytes=node.out_bytes, inputs=inputs, kind=node.kind)
         return g
+
+
+def build_stage_fns(model: GraphModel, params: Params, plan: Any,
+                    devices: Optional[Sequence[Any]] = None
+                    ) -> List[Callable[[Dict[str, jax.Array]],
+                                       Dict[str, jax.Array]]]:
+    """One stage function per stage of ``plan`` (a ``PlacementPlan`` over
+    ``model.to_layer_graph()``), for the host pipeline executor.
+
+    Stage ``s`` runs :meth:`GraphModel.stage_program` over
+    ``plan.stage_layers[s]``.  Its parameters are placed on ``devices[s]``
+    once, here; each call first moves its input boundary onto that device,
+    so consecutive stages may sit on different chips.  ``devices`` defaults
+    to ``jax.devices()[0]`` for every stage.  A stage returns what later
+    stages read: its own outputs plus the boundary tensors that cross it
+    untouched (a skip connection spanning the whole stage).
+
+    Each call waits for its outputs (``block_until_ready``) before it
+    returns, so the executor's per-stage busy time
+    (``snapshot()["stage_busy_s"]``) is device time, not dispatch time.
+    """
+    stage_layers = [list(ls) for ls in plan.stage_layers]
+    if devices is None:
+        devices = [jax.devices()[0]] * len(stage_layers)
+    if len(devices) != len(stage_layers):
+        raise ValueError(f"{len(devices)} devices for "
+                         f"{len(stage_layers)} stages")
+
+    def make(s: int) -> Callable:
+        names, dev = stage_layers[s], devices[s]
+        read_later = {i for ls in stage_layers[s + 1:] for n in ls
+                      for i in model.nodes[n].inputs}
+        passed = read_later - set(names)
+        program = model.stage_program(names)
+        stage_params = jax.device_put(
+            {n: params[n] for n in names if n in params}, dev)
+
+        def run(boundary: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
+            boundary = jax.device_put(boundary, dev)
+            out = program(stage_params, boundary)
+            out.update({k: v for k, v in boundary.items() if k in passed})
+            return jax.block_until_ready(out)
+
+        return run
+
+    return [make(s) for s in range(len(stage_layers))]
 
 
 # ---------------------------------------------------------------------------

@@ -25,24 +25,6 @@ import jax.numpy as jnp
 from . import attention as A
 
 
-@jax.custom_jvp
-def _opt_barrier(x):
-    """``jax.lax.optimization_barrier`` with a differentiation rule.
-
-    The barrier is semantically the identity, but jaxlib only grew its
-    built-in differentiation rule after 0.4.x — under ``value_and_grad``
-    older releases raise ``NotImplementedError: Differentiation rule for
-    'optimization_barrier'``.  The custom JVP passes tangents through
-    unchanged (the identity's exact derivative), keeping the barrier's
-    convert-motion fence in the primal computation only."""
-    return jax.lax.optimization_barrier(x)
-
-
-@_opt_barrier.defjvp
-def _opt_barrier_jvp(primals, tangents):
-    (x,), (t,) = primals, tangents
-    return _opt_barrier(x), t
-
 Params = Dict[str, Any]
 
 
@@ -357,16 +339,11 @@ def _mesh_info():
     info = current_mesh_info()
     if info is not None:
         return info
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        names = tuple(am.axis_names) if am is not None else ()
-        if not names:
-            return None
-        sizes = dict(zip(names, am.axis_sizes)) if hasattr(am, "axis_sizes") \
-            else {n: am.shape[n] for n in names}
-        return names, sizes
-    except Exception:       # pragma: no cover - older jax
+    am = jax.sharding.get_abstract_mesh()
+    names = tuple(am.axis_names)
+    if not names:
         return None
+    return names, dict(zip(names, am.axis_sizes))
 
 
 def attn_shard_constraints(q: jax.Array, k: jax.Array, v: jax.Array):
@@ -518,7 +495,7 @@ def forward(cfg: LMConfig, params: Params, batch: Dict[str, jax.Array],
         # buffer updates in f32 (2x the activation stack).
         if cfg.seq_shard_acts:
             x = seq_shard_constraint(x)
-        return fn(_opt_barrier(x), bp, positions), None
+        return fn(jax.lax.optimization_barrier(x), bp, positions), None
 
     x, _ = jax.lax.scan(body, x, params["blocks"])
     if last_token_only:
@@ -542,7 +519,7 @@ def forward_hidden(cfg: LMConfig, params: Params,
     def body(x, bp):
         if cfg.seq_shard_acts:
             x = seq_shard_constraint(x)
-        return fn(_opt_barrier(x), bp, positions), None
+        return fn(jax.lax.optimization_barrier(x), bp, positions), None
 
     x, _ = jax.lax.scan(body, x, params["blocks"])
     return x
@@ -572,7 +549,7 @@ def forward_decode(cfg: LMConfig, params: Params, tokens: jax.Array,
         # barrier: prevents CPU float-normalization from hoisting an f32
         # convert of the whole stacked cache out of the layer loop (a
         # CPU-only legalization; TPU dots consume bf16 natively)
-        kc, vc = _opt_barrier((kc, vc))
+        kc, vc = jax.lax.optimization_barrier((kc, vc))
         h = _norm(cfg, bp["ln1"], x)
         out, kc, vc = attn_block_decode(cfg, bp["attn"], h, kc, vc,
                                         new_len, pos)

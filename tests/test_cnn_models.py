@@ -9,7 +9,7 @@ from conftest import api_plan as plan
 from repro.core import EdgeTPUModel
 from repro.core.pipeline import PipelineExecutor
 from repro.models.cnn import REAL_CNNS, TABLE1, synthetic_cnn
-from repro.models.layers import GraphModel
+from repro.models.layers import Builder, GraphModel, build_stage_fns
 
 # NASNetMobile is a flagged structural approximation (params match, MACs
 # deviate); V2 ResNets share V1 MAC structure in our builders.
@@ -56,21 +56,15 @@ def test_mobilenet_forward():
     assert np.isfinite(np.asarray(y)).all()
 
 
-def _pipeline_vs_direct(model: GraphModel, n_stages: int):
-    g = model.to_layer_graph()
-    pl = plan(g, n_stages, "balanced_norefine")
+def _pipeline_vs_direct(model: GraphModel, n_stages: int, pl=None):
+    if pl is None:
+        pl = plan(model.to_layer_graph(), n_stages, "balanced_norefine")
     params = model.init(jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(1),
                           (1,) + model.input_shape)
     direct = model.apply(params, x)
 
-    def stage_fn(layers):
-        def run(boundary):
-            return model.apply_subset(params, boundary, layers)
-        return run
-
-    fns = [stage_fn(layers) for layers in pl.stage_layers]
-    execu = PipelineExecutor(fns)
+    execu = PipelineExecutor(build_stage_fns(model, params, pl))
     outs, _ = execu.run_batch([{GraphModel.INPUT: x}])
     np.testing.assert_allclose(np.asarray(outs[0][model.output]),
                                np.asarray(direct), rtol=2e-4, atol=2e-4)
@@ -96,6 +90,41 @@ def test_pipelined_branchy_model_equals_direct():
     x = b.gap(x, "gap")
     b.dense(x, 10, name="head")
     _pipeline_vs_direct(b.build(), 4)
+
+
+def test_stage_fns_pass_skip_through_whole_stage():
+    """A skip connection spanning whole stages of an uneven (comp) plan:
+    the stages between must hand the tensor on unchanged."""
+    b = Builder("skipnet", (16, 16), 3)
+    s = b.act(b.conv(b.model.INPUT, 8, 3, name="c1"), name="c1_relu")
+    x = s
+    for i in range(6):
+        x = b.conv(x, 8, 3, name=f"mid{i}")
+    x = b.add([x, s], name="skip_add")
+    b.dense(b.gap(x, name="pool"), 10, name="head")
+    model = b.build()
+    pl = plan(model.to_layer_graph(), 4, "comp")
+    assert not any("c1_relu" in ls or "skip_add" in ls
+                   for ls in pl.stage_layers[1:-1])
+    _pipeline_vs_direct(model, 4, pl)
+
+
+def test_stage_fns_place_on_device_and_wait():
+    model = synthetic_cnn(6, hw=16)
+    pl = plan(model.to_layer_graph(), 2, "balanced_norefine")
+    params = model.init(jax.random.PRNGKey(0))
+    dev = jax.devices()[0]
+    x = np.ones((2,) + model.input_shape, np.float32)    # host input
+    out = {GraphModel.INPUT: x}
+    for fn in build_stage_fns(model, params, pl, devices=[dev, dev]):
+        out = fn(out)
+        assert all(a.devices() == {dev} and a.is_fully_replicated
+                   for a in out.values())
+    np.testing.assert_allclose(np.asarray(out[model.output]),
+                               np.asarray(model.apply(params, x)),
+                               rtol=2e-4, atol=2e-4)
+    with pytest.raises(ValueError, match="1 devices for 2 stages"):
+        build_stage_fns(model, params, pl, devices=[dev])
 
 
 def test_min_stages_matches_paper_table5():

@@ -11,12 +11,13 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def run_with_devices(code: str, n_devices: int = 4, timeout: int = 560):
+def run_with_devices(code: str, n_devices: int = 4, timeout: int = 560,
+                     args=()):
     env = dict(os.environ)
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n_devices} "
                         + env.get("XLA_FLAGS", ""))
     env["PYTHONPATH"] = os.path.join(REPO, "src")
-    proc = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+    proc = subprocess.run([sys.executable, "-c", textwrap.dedent(code), *args],
                           capture_output=True, text=True, timeout=timeout,
                           env=env)
     assert proc.returncode == 0, f"STDOUT:\n{proc.stdout}\nERR:\n{proc.stderr}"
@@ -87,31 +88,49 @@ def test_spmd_pipeline_unequal_stage_counts():
     assert "OK" in out
 
 
-def test_spmd_cnn_executor_matches_direct():
-    """CNN GraphModel lowered via apply_subset ranges onto a 4-stage mesh:
-    fused per-stage branches + ppermute hops must reproduce model.apply."""
+@pytest.mark.parametrize("executor", ["spmd", "host"])
+def test_spmd_cnn_executor_matches_direct(executor):
+    """One 4-stage CNN plan on four devices must reproduce model.apply.
+    spmd: lowered via apply_subset ranges onto a 4-stage mesh (fused
+    per-stage branches + ppermute hops).  host: build_stage_fns stages
+    through the host executor, stage s on device s; each stage's output
+    must sit on its own device."""
     out = run_with_devices("""
-        import jax, jax.numpy as jnp, numpy as np
+        import sys
+        import jax, jax.numpy as jnp
         from repro.models.cnn import synthetic_cnn
-        from repro.api import DeploymentSpec
-        from repro.api import plan as api_plan
+        from repro.models.layers import GraphModel, build_stage_fns
+        from repro.api import DeploymentSpec, deploy
         from repro.launch.pipeline_spmd import SpmdPipelineExecutor
 
         model = synthetic_cnn(8, L=6, hw=32)
         params = model.init(jax.random.PRNGKey(0))
-        pl = api_plan(DeploymentSpec(stages=4,
-                                     strategy="balanced_norefine"),
-                      graph=model.to_layer_graph())
+        devs = jax.devices()[:4]
+        dep = deploy(DeploymentSpec(stages=4, strategy="balanced_norefine"),
+                     graph=model.to_layer_graph(),
+                     stage_fn_builder=lambda p: build_stage_fns(
+                         model, params, p, devices=devs))
         x = jax.random.normal(jax.random.PRNGKey(1), (8, 32, 32, 3))
         ref = model.apply(params, x)
-        with SpmdPipelineExecutor.for_model(model, params, pl,
-                                            n_microbatches=4,
-                                            batch_size=8) as ex:
-            got = ex(x)
+        if sys.argv[1] == "spmd":
+            with SpmdPipelineExecutor.for_model(model, params, dep.plan,
+                                                n_microbatches=4,
+                                                batch_size=8) as ex:
+                got = ex(x)
+        else:
+            h = {GraphModel.INPUT: x}
+            for s, fn in enumerate(dep.stage_functions()):
+                h = fn(h)
+                assert {d for a in h.values() for d in a.devices()} \
+                    == {devs[s]}, s
+            with dep.executor() as ex:
+                outs, _ = ex.run_batch([{GraphModel.INPUT: x[i:i + 1]}
+                                        for i in range(len(x))])
+            got = jnp.concatenate([o[model.output] for o in outs])
         err = float(jnp.max(jnp.abs(got - ref)))
         assert err < 1e-4, err
         print("OK", err)
-    """)
+    """, args=(executor,))
     assert "OK" in out
 
 
