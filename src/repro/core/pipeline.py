@@ -98,12 +98,29 @@ Liveness/health is observable via :meth:`PipelineExecutor.health_snapshot`:
 per-replica alive flags, heartbeat ages, consecutive item-failure counts,
 and per-stage hedge/re-dispatch counters.
 
+**Spans** (``submit(payload, spans=[...])``): an item submitted with a
+span list gets ``(name, start, end)`` tuples on ``time.perf_counter``'s
+clock appended to it, stage by stage: ``queue<s>`` from the entry to
+:meth:`PipelineExecutor.submit` (s = 0) or the end of stage ``s-1``'s call
+to the start of stage ``s``'s, then ``stage<s>``, the call itself (the two
+clock reads the busy counters add up), then whatever the stage function
+timed inside the call with :class:`span` (``stage<s>.hop`` ...).  A
+stacked micro-batch call gives its spans to every item in the stack.  A
+replicated stage may call one item twice (hedging, re-dispatch after a
+replica death): the first call to finish records its spans and later
+ones record nothing, so each name appears once per stage.  Items
+submitted without a list (``run_batch``, direct callers) record nothing.
+Each stage call is also a ``jax.profiler.TraceAnnotation`` named
+``repro.stage<s>``, as is each :class:`span`, so that a profile puts them
+on the device's clock; JAX is imported for that on first use only.
+
 This executor is the *paper-faithful* path (host-mediated transfers).  The
 pod-scale SPMD path (shard_map + ppermute over ICI) lives in
 launch/pipeline_spmd.py and consumes the same PlacementPlan.
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import queue
 import threading
@@ -115,6 +132,50 @@ _SHUTDOWN = object()      # terminates workers; forwarded by every stage
 _DEAD_TOKEN = object()    # a replica's one-time termination token on death
 _DISPATCHER_DONE = object()   # dispatcher -> merge: drain marker delivered
 _RETIRE = object()        # killer -> worker: your queue was reclaimed, exit
+
+# per worker thread: the span list of the item whose stage call is running
+# (None while no traced item is), which :class:`span` appends to
+_current = threading.local()
+_trace_annotation: Optional[Callable[[str], Any]] = None
+
+
+def _annotate(name: str):
+    """``jax.profiler.TraceAnnotation(name)``; a no-op without JAX."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = contextlib.nullcontext
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation(name)
+
+
+class span:
+    """Time one step of a stage call: ``with span("stage0.hop"): ...``.
+
+    Appends ``(name, start, end)`` to the span list of the executor item
+    whose stage call runs on this thread, and records nothing when there
+    is none (a direct call, ``run_batch``).  The step is also a
+    ``repro.<name>`` trace annotation."""
+
+    __slots__ = ("name", "_ann", "_t0")
+
+    def __init__(self, name: str):
+        self.name = name
+
+    def __enter__(self) -> "span":
+        self._ann = _annotate("repro." + self.name)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        spans = getattr(_current, "spans", None)
+        if spans is not None:
+            spans.append((self.name, self._t0, t1))
 
 
 class PipelineStopped(RuntimeError):
@@ -189,6 +250,19 @@ class _InFlight:
         self.slot = slot
         self.t_dispatch = time.monotonic()
         self.hedged = False
+
+
+class _Traced:
+    """A submitted item's span list, the time it joined the queue it
+    waits in, and the stage whose spans it expects next."""
+
+    __slots__ = ("spans", "t_queued", "stage")
+
+    def __init__(self, spans: List[Tuple[str, float, float]],
+                 t_queued: float):
+        self.spans = spans
+        self.t_queued = t_queued
+        self.stage = 0
 
 
 class _StageState:
@@ -298,6 +372,11 @@ class PipelineExecutor:
         self._hedge_stop = threading.Event()
         # seq -> Future (submit) or (_BatchSink, idx) (run_batch)
         self._pending: Dict[int, Any] = {}
+        # seq -> _Traced, for items submitted with a span list
+        self._traced: Dict[int, _Traced] = {}
+        self._traced_lock = threading.Lock()
+        self._span_names = [(f"queue{i}", f"stage{i}") for i in range(n)]
+        self._annotations = [f"repro.stage{i}" for i in range(n)]
         self._seq = itertools.count()
         self._started = False
         self._draining = False
@@ -357,6 +436,7 @@ class PipelineExecutor:
             self._queues = [queue.Queue(self.queue_size) for _ in range(n + 1)]
             self._threads = []
             self._pending = {}
+            self._traced = {}
             self._seq = itertools.count()
             self._draining = False
             # fresh failure-domain state: a restart resurrects every replica
@@ -404,11 +484,15 @@ class PipelineExecutor:
             self._started = True
             return self
 
-    def submit(self, payload: Any) -> "Future":
+    def submit(self, payload: Any,
+               spans: Optional[List[Tuple[str, float, float]]] = None
+               ) -> "Future":
         """Admit one item into the stream; returns a Future completed (with
         the tail stage's output, or the stage exception) as the item exits
         the pipeline.  Blocks when the head queue is full — the stream's
-        backpressure.  Starts the executor if needed."""
+        backpressure.  Starts the executor if needed.  Each stage appends
+        the item's spans to ``spans``, where given (module docstring)."""
+        t_entry = time.perf_counter()
         if not self._started:
             self.start()
         fut: Future = Future()
@@ -417,6 +501,8 @@ class PipelineExecutor:
                 raise RuntimeError(f"{self.name}: executor is stopping")
             seq = next(self._seq)
             self._pending[seq] = fut
+            if spans is not None:
+                self._traced[seq] = _Traced(spans, t_entry)
             self._queues[0].put((seq, payload))
         return fut
 
@@ -493,10 +579,14 @@ class PipelineExecutor:
         seq, payload = envelope
         if isinstance(payload, _Failed):
             return envelope
+        rec = self._traced.get(seq)
+        _current.spans = inner = [] if rec is not None else None
         try:
-            t0 = time.perf_counter()
-            out = fn(payload)
-            self._busy[i][slot] += time.perf_counter() - t0
+            with _annotate(self._annotations[i]):
+                t0 = time.perf_counter()
+                out = fn(payload)
+                t1 = time.perf_counter()
+            self._busy[i][slot] += t1 - t0
             self._items[i][slot] += 1
             self._consec_fails[i][slot] = 0
         except ReplicaFailure:
@@ -504,7 +594,26 @@ class PipelineExecutor:
         except BaseException as e:   # surface worker failures per item
             self._consec_fails[i][slot] += 1
             return (seq, _Failed(e))
+        finally:
+            _current.spans = None
+        if rec is not None:
+            self._record(rec, i, t0, t1, inner)
         return (seq, out)
+
+    def _record(self, rec: _Traced, i: int, t0: float, t1: float,
+                inner: List[Tuple[str, float, float]]) -> None:
+        """Append stage ``i``'s spans to a traced item: its wait in the
+        queue, the call ``[t0, t1]``, and the spans timed inside the call.
+        Only the first call of the item at this stage to finish records."""
+        queue_name, stage_name = self._span_names[i]
+        with self._traced_lock:
+            if rec.stage != i:
+                return          # a hedged or re-dispatched twin finished first
+            rec.stage = i + 1
+            rec.spans.append((queue_name, rec.t_queued, t0))
+            rec.spans.append((stage_name, t0, t1))
+            rec.spans.extend(inner)
+            rec.t_queued = t1
 
     def _apply_batched(self, i: int, slot: int,
                        bucket: List[Tuple[int, Any]]) -> List[Tuple[int, Any]]:
@@ -522,11 +631,14 @@ class PipelineExecutor:
         payloads = [p for _, p in bucket]
         rows = [int(p.shape[0]) for p in payloads]
         parts = None
+        recs = [self._traced.get(seq) for seq, _ in bucket]
+        _current.spans = inner = [] if any(recs) else None
         try:
             xp = _array_namespace(payloads[0])
-            t0 = time.perf_counter()
-            stacked_out = fn(xp.concatenate(payloads, axis=0))
-            dt = time.perf_counter() - t0
+            with _annotate(self._annotations[i]):
+                t0 = time.perf_counter()
+                stacked_out = fn(xp.concatenate(payloads, axis=0))
+                t1 = time.perf_counter()
             out_shape = getattr(stacked_out, "shape", None)
             if out_shape is not None and int(out_shape[0]) == sum(rows):
                 parts = []
@@ -540,12 +652,17 @@ class PipelineExecutor:
             raise       # the replica died, not the bucket
         except BaseException:
             pass        # per-item rerun pins the failure to the right item
+        finally:
+            _current.spans = None
         if parts is None:
             return [self._apply(i, slot, env) for env in bucket]
-        self._busy[i][slot] += dt
+        self._busy[i][slot] += t1 - t0
         self._items[i][slot] += len(bucket)
         self._mb_calls[i][slot] += 1
         self._mb_items[i][slot] += len(bucket)
+        for rec in recs:
+            if rec is not None:
+                self._record(rec, i, t0, t1, inner)
         return [(seq, part) for (seq, _), part in zip(bucket, parts)]
 
     def _stage_loop(self, i: int, q_in: queue.Queue, q_out: queue.Queue,
@@ -899,6 +1016,7 @@ class PipelineExecutor:
             if item is _SHUTDOWN:
                 return
             seq, payload = item
+            self._traced.pop(seq, None)
             entry = pending.pop(seq, None)
             if entry is None:
                 continue

@@ -18,7 +18,6 @@ used by the paper's Table 1.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -27,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.graph import LayerGraph
+from ..core.pipeline import span
 
 Params = Dict[str, Any]
 
@@ -129,11 +129,20 @@ class GraphModel:
             needed[self.output] = acts[self.output]
         return needed
 
-    def stage_program(self, layer_names: Sequence[str]) -> Callable:
+    def stage_program(self, layer_names: Sequence[str],
+                      stage: int) -> Callable:
         """``apply_subset`` over ``layer_names`` as one jitted program
-        ``(params, boundary) -> outputs``: what each pipeline stage runs."""
-        return jax.jit(functools.partial(self.apply_subset,
-                                         layer_names=tuple(layer_names)))
+        ``(params, boundary) -> outputs``: what pipeline stage ``stage``
+        runs.  It is named ``<model name>_stage<stage>``, which a profile
+        shows as the program's module."""
+        names = tuple(layer_names)
+
+        def program(params: Params, boundary: Dict[str, jax.Array]
+                    ) -> Dict[str, jax.Array]:
+            return self.apply_subset(params, boundary, layer_names=names)
+
+        program.__name__ = program.__qualname__ = f"{self.name}_stage{stage}"
+        return jax.jit(program)
 
     # -- lowering to the segmentation representation ----------------------------
     def to_layer_graph(self) -> LayerGraph:
@@ -164,6 +173,11 @@ def build_stage_fns(model: GraphModel, params: Params, plan: Any,
     Each call waits for its outputs (``block_until_ready``) before it
     returns, so the executor's per-stage busy time
     (``snapshot()["stage_busy_s"]``) is device time, not dispatch time.
+    A call times its three steps as spans of the executor item it serves
+    (``core.pipeline.span``): ``stage<s>.hop``, the boundary's move onto
+    the stage's device (for stage 0 the image's copy from the host),
+    ``stage<s>.dispatch``, the program's asynchronous dispatch, and
+    ``stage<s>.wait``, the wait for its outputs.
     """
     stage_layers = [list(ls) for ls in plan.stage_layers]
     if devices is None:
@@ -177,15 +191,20 @@ def build_stage_fns(model: GraphModel, params: Params, plan: Any,
         read_later = {i for ls in stage_layers[s + 1:] for n in ls
                       for i in model.nodes[n].inputs}
         passed = read_later - set(names)
-        program = model.stage_program(names)
+        program = model.stage_program(names, s)
         stage_params = jax.device_put(
             {n: params[n] for n in names if n in params}, dev)
+        hop, dispatch, wait = (f"stage{s}.{step}"
+                               for step in ("hop", "dispatch", "wait"))
 
         def run(boundary: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
-            boundary = jax.device_put(boundary, dev)
-            out = program(stage_params, boundary)
+            with span(hop):
+                boundary = jax.device_put(boundary, dev)
+            with span(dispatch):
+                out = program(stage_params, boundary)
             out.update({k: v for k, v in boundary.items() if k in passed})
-            return jax.block_until_ready(out)
+            with span(wait):
+                return jax.block_until_ready(out)
 
         return run
 

@@ -18,7 +18,9 @@ fed under load.
   streaming executor.  An admission thread moves requests from the batcher
   into the stream; each request's future completes it individually
   (``Request.event`` / ``Request.result`` / ``Request.error``) with
-  per-request latency recorded.  Busy-time and request accounting are
+  per-request latency and spans recorded (``Request.spans``: ``admit``
+  here, then the executor's ``queue<s>``/``stage<s>`` and the stage
+  functions' own).  Busy-time and request accounting are
   monotonic counters; :meth:`PipelinedModelServer.snapshot` returns deltas
   (throughput, per-stage busy seconds, latency percentiles) since the last
   snapshot.  Replicated stages in the plan (``replicas > 1``) map onto the
@@ -66,8 +68,8 @@ import queue
 import random
 import threading
 import time
-from collections import deque
-from typing import (Any, Callable, Dict, List, Optional, Sequence, Union)
+from typing import (Any, Callable, Dict, List, Optional, Sequence, Tuple,
+                    Union)
 
 from ..core.pipeline import PipelineExecutor, PipelineStopped, StageLost
 from ..core.placement import PlacementPlan
@@ -111,6 +113,12 @@ class Overloaded(RuntimeError):
 
 @dataclasses.dataclass
 class Request:
+    """One request and what became of it.  ``spans`` holds ``(name, start,
+    end)`` on ``time.perf_counter``'s clock, in the order they began:
+    ``admit`` from ``t_submit`` to the admission loop's hand-off to the
+    executor, then what the executor records (``queue<s>``, ``stage<s>``
+    and the steps inside each call, ``core.pipeline``).  A re-admitted
+    request's next ``admit`` starts where its last span ended."""
     rid: int
     payload: Any
     t_submit: float = dataclasses.field(default_factory=time.perf_counter)
@@ -125,6 +133,8 @@ class Request:
     # router chains member-server completions back to its own requests
     # this way); must not block — it runs on the executor's collector
     on_done: Optional[Callable[["Request"], None]] = None
+    spans: List[Tuple[str, float, float]] = dataclasses.field(
+        default_factory=list)
 
     @property
     def latency(self) -> float:
@@ -203,7 +213,6 @@ class PipelinedModelServer:
                  microbatch_wait_s: float = 0.0,
                  hedge_after: Optional[float] = None,
                  stage_loss_retries: int = 0,
-                 latency_window: int = 4096,
                  deadline_s: Optional[float] = None,
                  shed_policy: str = "none",
                  backoff_base_s: float = 0.05,
@@ -257,7 +266,6 @@ class PipelinedModelServer:
         # total rebases over the retired epochs so snapshot()'s ``totals``
         # block stays monotonic across hot-swaps
         self._items_epoch_base = 0
-        self._recent_lat: deque = deque(maxlen=latency_window)
         self._window_lat: List[float] = []
         self._snap_state = {"t": time.perf_counter(),
                             "busy": self.executor.busy_snapshot(),
@@ -384,8 +392,11 @@ class PipelinedModelServer:
                     self._finish(req, None, Overloaded(
                         req.rid, retry_after, est))
                     return
+        spans = req.spans
+        spans.append(("admit", spans[-1][2] if spans else req.t_submit,
+                      time.perf_counter()))
         try:
-            fut = self.executor.submit(req.payload)
+            fut = self.executor.submit(req.payload, spans=spans)
         except RuntimeError as e:       # executor stopping under our feet
             self._finish(req, None, PipelineStopped(str(e)))
             return
@@ -452,7 +463,6 @@ class PipelinedModelServer:
                     self.stats["deadline_exceeded"] += 1
             if not isinstance(error, (Overloaded, DeadlineExceeded)):
                 # shed/expired latencies are not service latencies
-                self._recent_lat.append(lat)
                 self._window_lat.append(lat)
         req.event.set()
         if req.on_done is not None:

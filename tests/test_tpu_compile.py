@@ -97,8 +97,8 @@ def test_resnet50_stage_program_compiles(resnet50, one_chip):
     boundary = {name: jax.ShapeDtypeStruct((1,) + shape, jnp.float32,
                                            sharding=one_chip)
                 for name, shape in bounds[1]}
-    compiled = model.stage_program(layers).lower(stage_params,
-                                                 boundary).compile()
+    compiled = model.stage_program(layers, 1).lower(
+        stage_params, boundary).compile()
     _fits_one_chip(compiled)
 
 
