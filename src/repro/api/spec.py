@@ -73,10 +73,14 @@ class DeploymentSpec:
 
     Serving policy (consumed by :class:`~repro.api.deploy.Deployment`)
     ------------------------------------------------------------------
-    ``max_batch`` / ``max_wait_s`` (admission micro-batching),
+    ``max_batch`` (most requests admission hands on at once),
     ``queue_size`` (inter-stage backpressure), ``microbatch`` /
     ``microbatch_wait_s`` (stage-level shape-bucketed dynamic
-    micro-batching).
+    micro-batching: the only place requests are held to form a stacked
+    call).  Admission hands requests on as soon as they arrive.
+    ``max_wait_s`` is deprecated: the streaming server no longer waits
+    out an admission window, and the field is kept only so that existing
+    specs stay valid.
 
     ``backend`` — which execution tier ``Deployment.executor()`` builds:
     ``"host"`` (default; the threaded

@@ -8,11 +8,13 @@ straight into the executor's stream (``PipelineExecutor.submit``), so the
 pipeline never drains and refills at a batch boundary and every stage stays
 fed under load.
 
-* :class:`MicroBatcher` — gathers requests into a batch of up to
-  ``max_batch``, waiting at most ``max_wait_s`` from *entry* (latency
-  bound).  Under the streaming server this bounds admission-loop wakeups,
-  not pipeline occupancy: admitted requests overlap in flight regardless
-  of which gather window they arrived in.
+* :class:`MicroBatcher` — the request queue in front of admission.  The
+  admission loop is work-conserving: it takes what is already queued (up
+  to ``max_batch``) and hands it on at once, never waiting for more.
+  Stacking items into one stage call is the executor's job
+  (``microbatch`` / ``microbatch_wait_s``), so admission holds nothing
+  back.  The server's ``max_wait_s`` is deprecated and unused; it is
+  still accepted so that existing callers and specs stay valid.
 * :class:`PipelinedModelServer` — a PlacementPlan + per-stage functions
   (from GraphModel.apply_subset or the LM stage executor) over a persistent
   streaming executor.  An admission thread moves requests from the batcher
@@ -78,6 +80,10 @@ from ..core.placement import PlacementPlan
 # were reused (or GC'd and their addresses recycled) across requests
 _RID = itertools.count()
 
+# how long the admission loop blocks for a first request before it looks
+# at the stop flag again
+_IDLE_POLL_S = 0.1
+
 
 class DeadlineExceeded(RuntimeError):
     """Completion error for a request that outlived its deadline — either
@@ -142,9 +148,11 @@ class Request:
 
 
 class MicroBatcher:
-    def __init__(self, max_batch: int = 15, max_wait_s: float = 0.02):
+    """The request queue in front of admission: :meth:`next_ready` takes
+    what is already queued, up to ``max_batch``."""
+
+    def __init__(self, max_batch: int = 15):
         self.max_batch = max_batch
-        self.max_wait_s = max_wait_s
         self.q: "queue.Queue[Request]" = queue.Queue()
 
     def submit(self, payload: Any, rid: Optional[int] = None,
@@ -156,21 +164,16 @@ class MicroBatcher:
         self.q.put(req)
         return req
 
-    def next_batch(self, block: bool = True) -> List[Request]:
-        # the deadline starts at entry: the wait for the *first* request
-        # counts against it, so the worst case is max_wait_s, not 2x
-        deadline = time.perf_counter() + self.max_wait_s
-        batch: List[Request] = []
+    def next_ready(self) -> List[Request]:
+        """Wait up to ``_IDLE_POLL_S`` for a first request, then take what
+        is already queued, up to ``max_batch``, without waiting for more."""
         try:
-            batch.append(self.q.get(block=block, timeout=self.max_wait_s))
+            batch = [self.q.get(timeout=_IDLE_POLL_S)]
         except queue.Empty:
-            return batch
+            return []
         while len(batch) < self.max_batch:
-            remaining = deadline - time.perf_counter()
-            if remaining <= 0:
-                break
             try:
-                batch.append(self.q.get(timeout=remaining))
+                batch.append(self.q.get_nowait())
             except queue.Empty:
                 break
         return batch
@@ -203,6 +206,8 @@ class PipelinedModelServer:
     individually by the executor's collector.  Use as a context manager
     (or call :meth:`stop`) for a clean shutdown — in-flight requests are
     then completed with :class:`PipelineStopped` rather than left hanging.
+    ``max_wait_s`` is deprecated and ignored: admission holds nothing
+    back (see the module docstring).
     """
 
     def __init__(self, plan: PlacementPlan,
@@ -249,7 +254,7 @@ class PipelinedModelServer:
         self._backoff_rng = random.Random(backoff_seed)
         self._stage_lost_listeners: List[Callable[[int], None]] = []
         self.executor = self._make_executor(plan, self.stage_fns)
-        self.batcher = MicroBatcher(max_batch, max_wait_s)
+        self.batcher = MicroBatcher(max_batch)
         self._stop_evt = threading.Event()
         self._admission = threading.Lock()   # held to pause admission
         self._thread: Optional[threading.Thread] = None
@@ -341,14 +346,14 @@ class PipelinedModelServer:
     # -- streaming API -------------------------------------------------------
     def start(self) -> None:
         """Start the admission loop: requests flow from the batcher into
-        the executor's stream as they arrive."""
+        the executor's stream as they arrive, without a gather window."""
         if self._thread is not None:
             return
         self._stop_evt.clear()
 
         def loop():
             while not self._stop_evt.is_set():
-                batch = self.batcher.next_batch()
+                batch = self.batcher.next_ready()
                 if not batch:
                     continue
                 with self._admission:

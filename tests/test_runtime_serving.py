@@ -142,12 +142,13 @@ def test_pipeline_time_matches_model():
 # serving
 # ---------------------------------------------------------------------------
 def test_microbatcher_gathers_up_to_max():
-    mb = MicroBatcher(max_batch=4, max_wait_s=0.05)
+    mb = MicroBatcher(max_batch=4)
     for i in range(6):
         mb.submit(i)
-    b1 = mb.next_batch()
-    b2 = mb.next_batch()
-    assert len(b1) == 4 and len(b2) == 2
+    b1 = mb.next_ready()
+    b2 = mb.next_ready()
+    assert [r.payload for r in b1] == [0, 1, 2, 3]
+    assert [r.payload for r in b2] == [4, 5]
 
 
 def test_pipelined_server_end_to_end():

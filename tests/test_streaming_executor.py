@@ -363,27 +363,90 @@ def test_elastic_planner_resize_server_hook():
 # ---------------------------------------------------------------------------
 # MicroBatcher + Request satellites
 # ---------------------------------------------------------------------------
-def test_microbatcher_deadline_starts_at_entry():
-    """Waiting for the *first* request counts against max_wait_s: worst
-    case is one window, not two (the old double-wait)."""
-    mb = MicroBatcher(max_batch=8, max_wait_s=0.2)
-
-    def late_put():
-        time.sleep(0.12)
-        mb.submit(1)
-
-    threading.Thread(target=late_put, daemon=True).start()
-    t0 = time.perf_counter()
-    batch = mb.next_batch()
-    dt = time.perf_counter() - t0
-    assert len(batch) == 1
-    assert dt < 0.32                       # old behavior: ~0.12 + 0.2
-
 def test_microbatcher_empty_wait_is_bounded():
-    mb = MicroBatcher(max_batch=4, max_wait_s=0.05)
+    mb = MicroBatcher(max_batch=4)
     t0 = time.perf_counter()
-    assert mb.next_batch() == []
+    assert mb.next_ready() == []
     assert time.perf_counter() - t0 < 0.2
+
+
+def test_microbatcher_next_ready_hands_on_first_arrival_at_once():
+    """A request that arrives while next_ready blocks is returned at once,
+    alone: nothing waits for a second one."""
+    mb = MicroBatcher(max_batch=8)
+
+    def late_puts():
+        time.sleep(0.03)
+        mb.submit(1)
+        time.sleep(0.3)
+        mb.submit(2)
+
+    threading.Thread(target=late_puts, daemon=True).start()
+    batch = []
+    while not batch:                      # an idle poll may come first
+        batch = mb.next_ready()
+    assert time.perf_counter() - batch[0].t_submit < 0.1
+    assert [r.payload for r in batch] == [1]
+
+
+def _admit_s(req):
+    return sum(b - a for name, a, b in req.spans if name == "admit")
+
+
+def test_per_item_admission_hands_on_without_window():
+    """A lone request is handed on at once: the deprecated max_wait_s of
+    0.5 s holds nothing back."""
+    srv, _ = _toy_server(max_batch=15, max_wait_s=0.5)
+    srv.start()
+    try:
+        for i in range(3):
+            r = srv.submit(i)
+            assert r.event.wait(5) and r.error is None
+            assert r.result == (i + 1) * 2 - 3
+            assert _admit_s(r) < 0.1
+    finally:
+        srv.stop()
+    assert srv.stats["admitted"] == 3
+
+
+def test_stacking_server_admits_at_once_and_executor_stacks():
+    """With stage 0 stacking (microbatch=4), admission still holds
+    nothing back; the executor's microbatch_wait_s forms the bucket."""
+    srv, _ = _toy_server(max_batch=4, max_wait_s=0.5, microbatch=4,
+                         microbatch_wait_s=0.2)
+    assert srv.executor.microbatch[0] == 4
+    srv.start()
+    try:
+        reqs = [srv.submit(np.full((1, 3), float(i))) for i in range(4)]
+        for i, r in enumerate(reqs):
+            assert r.event.wait(5) and r.error is None
+            assert r.result.shape == (1, 3)
+            assert float(r.result[0, 0]) == (i + 1) * 2 - 3
+            assert _admit_s(r) < 0.1
+    finally:
+        srv.stop()
+    snap = srv.executor.microbatch_snapshot()
+    assert snap["calls"][0] >= 1 and snap["items"][0] >= 2
+
+
+def test_admission_hands_on_at_once_across_reconfigure():
+    srv, _ = _toy_server(max_batch=4, max_wait_s=0.5)
+    srv.start()
+    try:
+        r = srv.submit(1)
+        assert r.event.wait(5) and r.result == 1
+        assert _admit_s(r) < 0.1
+        g = synthetic_cnn(600).to_layer_graph()
+        srv.reconfigure(plan(g, 2, "balanced_norefine"),
+                        [lambda x: x + 10, lambda x: x * 3])
+        for i in range(2):
+            r = srv.submit(i)
+            assert r.event.wait(5) and r.error is None
+            assert r.result == (i + 10) * 3
+            assert _admit_s(r) < 0.1
+    finally:
+        srv.stop()
+    assert srv.stats["admitted"] == 3
 
 
 def test_request_ids_unique_across_reused_payloads():
