@@ -134,7 +134,9 @@ _DISPATCHER_DONE = object()   # dispatcher -> merge: drain marker delivered
 _RETIRE = object()        # killer -> worker: your queue was reclaimed, exit
 
 # per worker thread: the span list of the item whose stage call is running
-# (None while no traced item is), which :class:`span` appends to
+# (None while no traced item is), which :class:`span` appends to, and the
+# thread's ``(hop-bytes slots of its stage, its slot)``, which
+# :func:`count_hop_bytes` adds to
 _current = threading.local()
 _trace_annotation: Optional[Callable[[str], Any]] = None
 
@@ -176,6 +178,16 @@ class span:
         spans = getattr(_current, "spans", None)
         if spans is not None:
             spans.append((self.name, self._t0, t1))
+
+
+def count_hop_bytes(n: int) -> None:
+    """Add ``n`` to the bytes that the stage call running on this thread
+    moves onto its device (:meth:`PipelineExecutor.hop_bytes_snapshot`).
+    A call outside the executor's workers counts nothing."""
+    slot = getattr(_current, "hop_bytes", None)
+    if slot is not None:
+        counters, i = slot
+        counters[i] += n
 
 
 class PipelineStopped(RuntimeError):
@@ -353,6 +365,9 @@ class PipelineExecutor:
         # writer discipline: busy/items deltas = observed per-item stage
         # time, the live-telemetry signal the self-healing loop refits from
         self._items = [[0] * r for r in self.replicas]
+        # bytes the stage calls moved onto their devices (the stage
+        # function reports them with count_hop_bytes), same discipline
+        self._hop_bytes = [[0] * r for r in self.replicas]
         # micro-batching amortization counters (calls / items): one slot
         # per (stage, replica) like _busy, so concurrent replica workers
         # never lose updates; monotonic
@@ -677,6 +692,7 @@ class PipelineExecutor:
         *bypass* loop — it keeps draining its queue, forwarding every
         envelope as ``_Failed(StageLost)`` so the stream never stalls and
         shutdown still cascades."""
+        _current.hop_bytes = (self._hop_bytes[i], slot)
         k = self.microbatch[i]
         carry: Any = None
         while True:
@@ -1045,6 +1061,12 @@ class PipelineExecutor:
         telemetry the self-healing control loop feeds back into the
         planner's cost model (``runtime.selfheal``)."""
         return [sum(slots) for slots in self._items]
+
+    def hop_bytes_snapshot(self) -> List[int]:
+        """Monotonic per-stage bytes that the stage calls moved onto their
+        devices, as the stage functions report them
+        (:func:`count_hop_bytes`; summed over replicas)."""
+        return [sum(slots) for slots in self._hop_bytes]
 
     def microbatch_snapshot(self) -> Dict[str, List[int]]:
         """Monotonic per-stage micro-batching counters (summed over

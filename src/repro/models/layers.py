@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.graph import LayerGraph
-from ..core.pipeline import span
+from ..core.pipeline import count_hop_bytes, span
 
 Params = Dict[str, Any]
 
@@ -174,10 +174,14 @@ def build_stage_fns(model: GraphModel, params: Params, plan: Any,
     returns, so the executor's per-stage busy time
     (``snapshot()["stage_busy_s"]``) is device time, not dispatch time.
     A call times its three steps as spans of the executor item it serves
-    (``core.pipeline.span``): ``stage<s>.hop``, the boundary's move onto
-    the stage's device (for stage 0 the image's copy from the host),
-    ``stage<s>.dispatch``, the program's asynchronous dispatch, and
-    ``stage<s>.wait``, the wait for its outputs.
+    (``core.pipeline.span``): ``stage<s>.hop``, the call that moves the
+    boundary onto the stage's device (for stage 0 the image's copy from
+    the host; it returns once the copy is queued, and the copy ends
+    inside the wait), ``stage<s>.dispatch``, the program's asynchronous
+    dispatch, and ``stage<s>.wait``, the wait for its outputs.  It also
+    adds the bytes of the boundary it moves to its stage's hop-bytes
+    counter (``core.pipeline.count_hop_bytes``, read as
+    ``snapshot()["stage_hop_bytes"]``).
     """
     stage_layers = [list(ls) for ls in plan.stage_layers]
     if devices is None:
@@ -198,6 +202,7 @@ def build_stage_fns(model: GraphModel, params: Params, plan: Any,
                                for step in ("hop", "dispatch", "wait"))
 
         def run(boundary: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
+            count_hop_bytes(sum(v.nbytes for v in boundary.values()))
             with span(hop):
                 boundary = jax.device_put(boundary, dev)
             with span(dispatch):

@@ -275,6 +275,7 @@ class PipelinedModelServer:
         self._snap_state = {"t": time.perf_counter(),
                             "busy": self.executor.busy_snapshot(),
                             "items": self.executor.items_snapshot(),
+                            "hop_bytes": self.executor.hop_bytes_snapshot(),
                             "requests": 0, "completed": 0, "failed": 0,
                             "retried": 0, "shed": 0,
                             "deadline_exceeded": 0}
@@ -479,7 +480,8 @@ class PipelinedModelServer:
     # -- accounting ----------------------------------------------------------
     def snapshot(self) -> Dict[str, Any]:
         """Deltas since the previous snapshot: requests finished,
-        throughput, per-stage busy seconds, and latency percentiles over
+        throughput, per-stage busy seconds, items and hop bytes (what the
+        stage calls moved onto their devices), and latency percentiles over
         the interval's completed requests.  Counters stay monotonic — this
         is the only reset-free way to watch a continuous stream.
 
@@ -493,6 +495,7 @@ class PipelinedModelServer:
         now = time.perf_counter()
         busy = self.executor.busy_snapshot()
         items = self.executor.items_snapshot()
+        hop_bytes = self.executor.hop_bytes_snapshot()
         with self._stats_lock:
             window = self._window_lat
             self._window_lat = []
@@ -509,6 +512,7 @@ class PipelinedModelServer:
         busy_d = [b - a for a, b in zip(prev["busy"], busy)]
         items_d = [b - a for a, b in
                    zip(prev.get("items", items), items)]
+        hop_d = [b - a for a, b in zip(prev["hop_bytes"], hop_bytes)]
         # every field below is neutral (0 / 0.0 / empty-sample record) on
         # an empty delta window — a zero-completion interval must never
         # crash or emit NaN (latency_percentiles handles the empty sample)
@@ -524,6 +528,9 @@ class PipelinedModelServer:
             "throughput_rps": (done / dt) if dt > 0 else 0.0,
             "stage_busy_s": busy_d,
             "stage_items": items_d,
+            # bytes each stage's calls moved onto its device (stage 0: the
+            # inputs from the host; stage s >= 1: the hop from stage s-1)
+            "stage_hop_bytes": hop_d,
             # per-item observed stage time — the live-telemetry signal the
             # self-healing loop (runtime.selfheal) refits the cost model
             # from; 0.0 (not NaN) for stages that applied nothing
@@ -551,6 +558,7 @@ class PipelinedModelServer:
             },
         }
         self._snap_state = {"t": now, "busy": busy, "items": items,
+                            "hop_bytes": hop_bytes,
                             "requests": requests, "completed": completed,
                             "failed": failed, "retried": retried,
                             "shed": shed,
@@ -582,6 +590,8 @@ class PipelinedModelServer:
             # rebase busy/items deltas onto the new executor's counters
             self._snap_state["busy"] = self.executor.busy_snapshot()
             self._snap_state["items"] = self.executor.items_snapshot()
+            self._snap_state["hop_bytes"] = \
+                self.executor.hop_bytes_snapshot()
             # the new plan invalidates the old service-pace signal
             self._pace_ewma = None
             self._last_done_t = None

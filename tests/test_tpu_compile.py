@@ -119,6 +119,36 @@ def test_resnet50_spmd_pipeline_compiles(resnet50, topo):
     _fits_one_chip(compiled)
 
 
+@pytest.fixture(scope="module")
+def resnet152():
+    model = REAL_CNNS["ResNet152"]()
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    pl = plan(DeploymentSpec(model="cnn:ResNet152", stages=4),
+              graph=model.to_layer_graph())
+    return model, shapes, pl
+
+
+@pytest.mark.parametrize("stage", range(4))
+def test_resnet152_stage_program_compiles_on_its_chip(resnet152, topo,
+                                                      stage):
+    """Each stage of the benchmark's four-stage ResNet152 plan, as
+    ``build_stage_fns`` runs it with stage s on chip s; every cut after
+    the first image falls mid-block, so two tensors cross it."""
+    model, shapes, pl = resnet152
+    bounds, _ = cnn_boundary_specs(model, pl)
+    assert len(bounds[stage]) == (1 if stage == 0 else 2)
+    chip = SingleDeviceSharding(topo.devices[stage])
+    layers = pl.stage_layers[stage]
+    stage_params = _placed({n: shapes[n] for n in layers if n in shapes},
+                           chip)
+    boundary = {name: jax.ShapeDtypeStruct((1,) + shape, jnp.float32,
+                                           sharding=chip)
+                for name, shape in bounds[stage]}
+    compiled = model.stage_program(layers, stage).lower(
+        stage_params, boundary).compile()
+    _fits_one_chip(compiled)
+
+
 BF16, F32, I8 = jnp.bfloat16, jnp.float32, jnp.int8
 
 # one call per Pallas kernel at a real width: qwen3-1.7b attention
